@@ -1096,16 +1096,13 @@ def paper_bandwidth():
             hlo = step.lower(state, jnp.zeros((2, 64, 784))).compile().as_text()
             print(name, collective_bytes_from_hlo(hlo)["total"])
     """)
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, env=env, timeout=560)
+                       text=True, env=_cpu_child_env(), timeout=560)
+    if r.returncode != 0:
+        raise RuntimeError(f"paper_bandwidth child rc={r.returncode}: "
+                           f"{r.stderr[-400:]}")
     rows = dict(line.split() for line in r.stdout.strip().splitlines()
                 if line.strip())
-    if not rows:
-        emit("paper_bandwidth/FAIL", 0.0, r.stderr[-120:])
-        return
     dense = float(rows["dense_maxabs"])
     for name, v in rows.items():
         emit(f"paper_bandwidth/{name}", 0.0,
@@ -1163,14 +1160,24 @@ _QUICK_DRYRUN = [("tinyllama-1.1b", "train_4k"),
                  ("mamba2-780m", "train_4k")]
 
 
+def _cpu_child_env() -> dict:
+    """Environment for the benchmark's child processes.  They are CPU
+    cost-model jobs (forced host devices, 512-device dry-run compiles):
+    ``JAX_PLATFORMS=cpu`` keeps them off the accelerator, which belongs
+    to this process once it has touched JAX."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    env.pop("XLA_FLAGS", None)
+    return env
+
+
 def _gen_dryrun_artifacts():
     """Produce experiments/dryrun/*.json in a SUBPROCESS — dryrun pins
     XLA_FLAGS (512 fake host devices) at import, which must not leak into
     this process's already-initialized JAX runtime."""
     import subprocess
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env.pop("XLA_FLAGS", None)
+    env = _cpu_child_env()
     cmds = ([[sys.executable, "-m", "repro.launch.dryrun",
               "--arch", a, "--shape", s] for a, s in _QUICK_DRYRUN]
             if QUICK else
@@ -1179,8 +1186,8 @@ def _gen_dryrun_artifacts():
         r = subprocess.run(cmd, capture_output=True, text=True, env=env,
                            timeout=300 if QUICK else 3600)
         if r.returncode != 0:
-            print(f"# dryrun {' '.join(cmd[3:])} rc={r.returncode}: "
-                  f"{r.stderr[-160:]}", file=sys.stderr)
+            raise RuntimeError(f"dryrun {' '.join(cmd[3:])} "
+                               f"rc={r.returncode}: {r.stderr[-400:]}")
 
 
 def roofline_table():
@@ -1314,6 +1321,8 @@ def main() -> None:
     if unknown:
         sys.exit(f"unknown benchmark(s) {unknown}; "
                  f"choose from: {', '.join(BENCHES)}")
+    from repro.launch.compile_cache import enable_compile_cache
+    print(f"# compile cache: {enable_compile_cache()}", file=sys.stderr)
     print("name,us_per_call,derived")
     for n in names:
         BENCHES[n]()

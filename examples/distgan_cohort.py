@@ -17,9 +17,11 @@ from repro.core.gan import MLPGanConfig, make_mlp_pair
 from repro.core.protocol import run_distgan
 from repro.data.federated import dirichlet_partition
 from repro.data.mixtures import GaussianMixture
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     U, C, steps, B = 64, 8, 400, 64
     modes = 8
 

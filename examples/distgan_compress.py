@@ -33,9 +33,11 @@ from repro.core.spec import (BackendSpec, CombineSpec, CompressionSpec,
                              EngineSpec, FederationSpec, ParticipationSpec)
 from repro.data.federated import FederatedDataset
 from repro.data.mixtures import GaussianMixture
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     quick = "--quick" in sys.argv[1:]
     C, B, modes = 8, 64, 8
     steps = 200 if quick else 800

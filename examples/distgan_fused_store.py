@@ -36,9 +36,11 @@ from repro.core.spec import (BackendSpec, EngineSpec, FederationSpec,
                              ParticipationSpec)
 from repro.data.federated import FederatedDataset
 from repro.data.mixtures import GaussianMixture
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     U, C, K, steps, B = 512, 8, 16, 192, 64
 
     mix = GaussianMixture.ring(8)

@@ -20,6 +20,7 @@ from repro.core.gan import MLPGanConfig, make_mlp_pair
 from repro.core.protocol import effective_epoch_time, run_distgan
 from repro.data.federated import FederatedDataset, federated_split
 from repro.data.mixtures import digits_like_mixture, template_coverage
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def build_dataset(n_per_class=400, size=28):
@@ -37,6 +38,7 @@ def build_dataset(n_per_class=400, size=28):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=1500)
     ap.add_argument("--batch", type=int, default=64)

@@ -33,9 +33,11 @@ from repro.core.spec import (BackendSpec, CombineSpec, CompressionSpec,
                              FederationSpec, ParticipationSpec)
 from repro.data.federated import FederatedDataset
 from repro.data.mixtures import GaussianMixture
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args()

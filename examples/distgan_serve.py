@@ -35,10 +35,12 @@ from repro.core.spec import (BackendSpec, FederationSpec,
                              ParticipationSpec, ServeSpec)
 from repro.data.federated import FederatedDataset
 from repro.data.mixtures import make_user_domains
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import GenerationService
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args()

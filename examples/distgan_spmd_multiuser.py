@@ -3,14 +3,16 @@ shard_map), the paper's §5.7 large-scale experiment.  Raw data is sharded
 over the `users` axis and never crosses it — only selected deltas
 (approach 1) / D probabilities and G gradients (approach 2) do.
 
-On the 512-chip production mesh the same code runs with users on the
-`pod` axis; here it runs on 5 forced host devices.
+A CPU demo: it sets ``JAX_PLATFORMS=cpu`` and forces 5 host devices, so
+it never takes an accelerator.  On chips, the same engine runs with one
+user per chip (``chip_smoke.py --chips 4`` drives the spmd backend).
 
   PYTHONPATH=src python examples/distgan_spmd_multiuser.py
 """
 
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=5")
 
 import numpy as np  # noqa: E402
@@ -21,10 +23,12 @@ from repro.core.approaches import DistGANConfig, init_state  # noqa: E402
 from repro.core.engine import make_spmd_engine, run_scanned  # noqa: E402
 from repro.core.gan import MLPGanConfig, make_mlp_pair  # noqa: E402
 from repro.data.mixtures import make_user_domains  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_users_mesh  # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     U, steps, B = 5, 800, 64
     pair = make_mlp_pair(MLPGanConfig(data_dim=2, z_dim=16, g_hidden=128,
                                       d_hidden=128))

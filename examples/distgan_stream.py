@@ -35,9 +35,11 @@ from repro.core.spec import (BackendSpec, CombineSpec, FederationSpec,
                              ParticipationSpec)
 from repro.data.federated import FederatedDataset
 from repro.data.mixtures import GaussianMixture
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     C, steps, B, modes = 8, 600, 64, 8
 
     mix = GaussianMixture.ring(modes)

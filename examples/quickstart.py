@@ -11,6 +11,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import get_config
 from repro.data.synthetic import TokenStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import greedy_decode
 from repro.launch.steps import make_train_step
 from repro.models import model as M
@@ -18,6 +19,7 @@ from repro.optim import adamw
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--steps", type=int, default=30)

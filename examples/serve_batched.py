@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.configs.base import get_config
 from repro.core.spec import DecodeSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import cache_nbytes, greedy_decode
 from repro.models import model as M
 from repro.serve.decode import DecodeEngine, DecodeRequest
@@ -59,6 +60,7 @@ def serve(arch: str, batch=4, prompt_len=16, gen=16):
 
 
 def main():
+    enable_compile_cache()
     for arch in ["tinyllama-1.1b", "mamba2-780m", "deepseek-v2-lite-16b"]:
         serve(arch)
 

@@ -110,8 +110,7 @@ def make_spmd_engine(pair, fcfg: DistGANConfig, mesh, approach: str):
     """
     from jax.sharding import PartitionSpec as PS
 
-    from repro.core.spmd import (AXIS, _specs_for, make_spmd_body,
-                                 shard_map_compat)
+    from repro.core.spmd import AXIS, _specs_for, make_spmd_body
 
     body = make_spmd_body(pair, fcfg, approach)
 
@@ -129,8 +128,9 @@ def make_spmd_engine(pair, fcfg: DistGANConfig, mesh, approach: str):
                 return jax.lax.scan(_masked(body), st, (rs, vs))
             in_specs = (state_specs, PS(None, AXIS), PS())
 
-        fn = shard_map_compat(scanned, mesh, in_specs=in_specs,
-                              out_specs=(state_specs, metric_specs))
+        fn = jax.shard_map(scanned, mesh=mesh, in_specs=in_specs,
+                           out_specs=(state_specs, metric_specs),
+                           check_vma=False)
         return fn(state, reals) if valid is None else fn(state, reals, valid)
 
     return jax.jit(chunk, donate_argnums=(0,))
@@ -317,8 +317,7 @@ def make_spmd_cohort_engine(pair, fcfg: DistGANConfig, mesh, approach: str,
     """
     from jax.sharding import PartitionSpec as PS
 
-    from repro.core.spmd import (AXIS, make_spmd_cohort_round,
-                                 shard_map_compat)
+    from repro.core.spmd import AXIS, make_spmd_cohort_round
 
     axis_size = mesh.shape[AXIS]
     assert axis_size == cohort_size, (
@@ -348,8 +347,9 @@ def make_spmd_cohort_engine(pair, fcfg: DistGANConfig, mesh, approach: str,
             in_specs = (carry_specs, PS(None, AXIS), PS(None, AXIS), PS())
             args = (cstate, reals, idx, valid)
 
-        fn = shard_map_compat(scanned, mesh, in_specs=in_specs,
-                              out_specs=(carry_specs, metric_specs))
+        fn = jax.shard_map(scanned, mesh=mesh, in_specs=in_specs,
+                           out_specs=(carry_specs, metric_specs),
+                           check_vma=False)
         return fn(*args)
 
     return jax.jit(chunk)  # not donated — see make_cohort_engine
@@ -367,8 +367,7 @@ def make_spmd_fused_store_engine(pair, fcfg: DistGANConfig, mesh,
     """
     from jax.sharding import PartitionSpec as PS
 
-    from repro.core.spmd import (AXIS, make_spmd_fused_store_round,
-                                 shard_map_compat)
+    from repro.core.spmd import AXIS, make_spmd_fused_store_round
 
     axis_size = mesh.shape[AXIS]
     assert axis_size == cohort_size, (
@@ -402,8 +401,9 @@ def make_spmd_fused_store_engine(pair, fcfg: DistGANConfig, mesh,
             in_specs = (carry_specs, PS(None, AXIS), PS(None, AXIS), PS())
             args = (cstate, reals, idx, valid)
 
-        fn = shard_map_compat(scanned, mesh, in_specs=in_specs,
-                              out_specs=(carry_specs, metric_specs))
+        fn = jax.shard_map(scanned, mesh=mesh, in_specs=in_specs,
+                           out_specs=(carry_specs, metric_specs),
+                           check_vma=False)
         return fn(*args)
 
     return jax.jit(chunk)  # not donated — see make_cohort_engine

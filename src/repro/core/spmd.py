@@ -36,19 +36,6 @@ from repro.optim import adamw, apply_updates
 AXIS = "users"
 
 
-def shard_map_compat(f, mesh, *, in_specs, out_specs):
-    """shard_map across jax versions: ``jax.shard_map`` + ``check_vma``
-    on current jax, ``jax.experimental.shard_map`` + ``check_rep`` on the
-    0.4.x line.  Replication checking is off in both (the GAN bodies mix
-    replicated and per-user state on purpose)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def _opts(fcfg):
     return (adamw(fcfg.g_lr, b1=fcfg.b1, b2=fcfg.b2),
             adamw(fcfg.d_lr, b1=fcfg.b1, b2=fcfg.b2))
@@ -559,8 +546,8 @@ def make_spmd_cohort_rows_engine(pair, fcfg: DistGANConfig, mesh,
 
         def step_ef(shared, d_rows, o_rows, res_rows, ages, wts, real):
             shared_specs, metric_specs, w_spec = _specs(shared, wts)
-            fn = shard_map_compat(
-                round_fn_ef, mesh,
+            fn = jax.shard_map(
+                round_fn_ef, mesh=mesh, check_vma=False,
                 in_specs=(shared_specs, PS(AXIS), PS(AXIS), PS(AXIS),
                           PS(AXIS), w_spec, PS(AXIS)),
                 out_specs=(shared_specs, PS(AXIS), PS(AXIS), PS(AXIS),
@@ -591,8 +578,8 @@ def make_spmd_cohort_rows_engine(pair, fcfg: DistGANConfig, mesh,
 
     def step(shared, d_rows, o_rows, ages, wts, real):
         shared_specs, metric_specs, w_spec = _specs(shared, wts)
-        fn = shard_map_compat(
-            round_fn, mesh,
+        fn = jax.shard_map(
+            round_fn, mesh=mesh, check_vma=False,
             in_specs=(shared_specs, PS(AXIS), PS(AXIS), PS(AXIS), w_spec,
                       PS(AXIS)),
             out_specs=(shared_specs, PS(AXIS), PS(AXIS), metric_specs))
@@ -651,9 +638,9 @@ def make_spmd_step(pair, fcfg: DistGANConfig, mesh, approach: str):
         state_specs = _specs_for(state, mesh)
         metric_specs = {"d_loss": PS(AXIS), "g_loss": PS(),
                         "kept_frac": PS()}
-        fn = shard_map_compat(body, mesh,
-                              in_specs=(state_specs, PS(AXIS)),
-                              out_specs=(state_specs, metric_specs))
+        fn = jax.shard_map(body, mesh=mesh, check_vma=False,
+                           in_specs=(state_specs, PS(AXIS)),
+                           out_specs=(state_specs, metric_specs))
         return fn(state, real)
 
     return jax.jit(step, donate_argnums=(0,))

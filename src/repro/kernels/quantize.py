@@ -31,7 +31,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.topk_select import BLOCK
+from repro.kernels.topk_select import (BLOCK, LANES, SUBLANES, from_tiles,
+                                       to_tiles)
+
+# A row's BLOCK-long column slice is one (8, 1024) tile of the
+# (R, nblocks * 8, 1024) view, so every block meets the TPU's (8, 128)
+# rule; the row axis is squeezed out of the kernel's view.
+_TILE = pl.BlockSpec((None, SUBLANES, LANES), lambda i, j: (i, j, 0))
+# Per-row scalars (scale, reciprocal, block maxima) and the seed live
+# whole in SMEM and are indexed by grid position.
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _hash_u01(row, col, seed):
@@ -46,38 +55,36 @@ def _hash_u01(row, col, seed):
     h = h ^ (h >> 15)
     h = h * jnp.uint32(0x846CA68B)
     h = h ^ (h >> 16)
-    return h.astype(jnp.float32) * jnp.float32(1.0 / 4294967296.0)
+    # top 24 bits -> exact f32 in [0, 1); Mosaic has no uint32 -> f32 cast
+    top = jax.lax.bitcast_convert_type(h >> 8, jnp.int32)
+    return top.astype(jnp.float32) * jnp.float32(1.0 / 16777216.0)
 
 
-def _row_block_absmax_kernel(x_ref, o_ref):
-    o_ref[0, 0] = jnp.max(jnp.abs(x_ref[...]))
+def _row_block_absmax_kernel(x_ref, o_ref, *, nblocks: int):
+    r, b = pl.program_id(0), pl.program_id(1)
+    o_ref[r * nblocks + b] = jnp.max(jnp.abs(x_ref[...]))
 
 
 def _quantize_kernel(inv_ref, x_ref, o_ref):
-    y = x_ref[...] * inv_ref[0, 0]
+    y = x_ref[...] * inv_ref[pl.program_id(0)]
     o_ref[...] = jnp.clip(jnp.round(y), -127.0, 127.0).astype(jnp.int8)
 
 
 def _quantize_sr_kernel(inv_ref, seed_ref, x_ref, o_ref):
-    y = jnp.clip(x_ref[...] * inv_ref[0, 0], -127.0, 127.0)
+    r, b = pl.program_id(0), pl.program_id(1)
+    y = jnp.clip(x_ref[...] * inv_ref[r], -127.0, 127.0)
     f = jnp.floor(y)
-    r = pl.program_id(0)
-    b = pl.program_id(1)
-    col = b * BLOCK + jax.lax.broadcasted_iota(jnp.int32, y.shape, 1)
+    col = (b * BLOCK
+           + jax.lax.broadcasted_iota(jnp.int32, y.shape, 0) * LANES
+           + jax.lax.broadcasted_iota(jnp.int32, y.shape, 1))
     row = jnp.full(y.shape, r, jnp.int32)
-    u = _hash_u01(row, col, seed_ref[0, 0])
+    u = _hash_u01(row, col, seed_ref[0])
     q = f + (u < (y - f)).astype(jnp.float32)
     o_ref[...] = jnp.clip(q, -127.0, 127.0).astype(jnp.int8)
 
 
 def _dequantize_kernel(scale_ref, q_ref, o_ref):
-    o_ref[...] = q_ref[...].astype(jnp.float32) * scale_ref[0, 0]
-
-
-def _pad_cols(x):
-    n = x.shape[1]
-    pad = (-n) % BLOCK
-    return jnp.pad(x, ((0, 0), (0, pad))), x.shape[1] + pad
+    o_ref[...] = q_ref[...].astype(jnp.float32) * scale_ref[pl.program_id(0)]
 
 
 def quantize_rows_pallas(x: jnp.ndarray, *, stochastic: bool = False,
@@ -88,20 +95,18 @@ def quantize_rows_pallas(x: jnp.ndarray, *, stochastic: bool = False,
     and is required iff ``stochastic``."""
     assert x.ndim == 2, f"quantize_rows wants stacked rows, got {x.shape}"
     r, n = x.shape
-    xp, npad = _pad_cols(x.astype(jnp.float32))
-    nblocks = npad // BLOCK
+    xp, nblocks = to_tiles(x.astype(jnp.float32))
 
     bmax = pl.pallas_call(
-        _row_block_absmax_kernel,
+        functools.partial(_row_block_absmax_kernel, nblocks=nblocks),
         grid=(r, nblocks),
-        in_specs=[pl.BlockSpec((1, BLOCK), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (i, j),
-                               memory_space=pltpu.SMEM),
-        out_shape=jax.ShapeDtypeStruct((r, nblocks), jnp.float32),
+        in_specs=[_TILE],
+        out_specs=_SMEM,
+        out_shape=jax.ShapeDtypeStruct((r * nblocks,), jnp.float32),
         interpret=interpret,
     )(xp)
 
-    scale = jnp.max(bmax, axis=1) / jnp.float32(127.0)
+    scale = jnp.max(bmax.reshape(r, nblocks), axis=1) / jnp.float32(127.0)
     inv = jnp.where(scale > 0, 1.0 / scale, 0.0).astype(jnp.float32)
 
     if stochastic:
@@ -109,28 +114,21 @@ def quantize_rows_pallas(x: jnp.ndarray, *, stochastic: bool = False,
         q = pl.pallas_call(
             _quantize_sr_kernel,
             grid=(r, nblocks),
-            in_specs=[pl.BlockSpec((1, 1), lambda i, j: (i, 0),
-                                   memory_space=pltpu.SMEM),
-                      pl.BlockSpec((1, 1), lambda i, j: (0, 0),
-                                   memory_space=pltpu.SMEM),
-                      pl.BlockSpec((1, BLOCK), lambda i, j: (i, j))],
-            out_specs=pl.BlockSpec((1, BLOCK), lambda i, j: (i, j)),
-            out_shape=jax.ShapeDtypeStruct((r, npad), jnp.int8),
+            in_specs=[_SMEM, _SMEM, _TILE],
+            out_specs=_TILE,
+            out_shape=jax.ShapeDtypeStruct(xp.shape, jnp.int8),
             interpret=interpret,
-        )(inv.reshape(r, 1),
-          jnp.asarray(seed, jnp.int32).reshape(1, 1), xp)
+        )(inv, jnp.asarray(seed, jnp.int32).reshape(1), xp)
     else:
         q = pl.pallas_call(
             _quantize_kernel,
             grid=(r, nblocks),
-            in_specs=[pl.BlockSpec((1, 1), lambda i, j: (i, 0),
-                                   memory_space=pltpu.SMEM),
-                      pl.BlockSpec((1, BLOCK), lambda i, j: (i, j))],
-            out_specs=pl.BlockSpec((1, BLOCK), lambda i, j: (i, j)),
-            out_shape=jax.ShapeDtypeStruct((r, npad), jnp.int8),
+            in_specs=[_SMEM, _TILE],
+            out_specs=_TILE,
+            out_shape=jax.ShapeDtypeStruct(xp.shape, jnp.int8),
             interpret=interpret,
-        )(inv.reshape(r, 1), xp)
-    return q[:, :n], scale
+        )(inv, xp)
+    return from_tiles(q, n), scale
 
 
 def dequantize_rows_pallas(q: jnp.ndarray, scale: jnp.ndarray, *,
@@ -138,16 +136,13 @@ def dequantize_rows_pallas(q: jnp.ndarray, scale: jnp.ndarray, *,
     """(q int8 (R, N), scale f32 (R,)) -> f32 (R, N): ``q * scale[r]``."""
     assert q.ndim == 2, f"dequantize_rows wants stacked rows, got {q.shape}"
     r, n = q.shape
-    qp, npad = _pad_cols(q)
-    nblocks = npad // BLOCK
+    qp, nblocks = to_tiles(q)
     out = pl.pallas_call(
         _dequantize_kernel,
         grid=(r, nblocks),
-        in_specs=[pl.BlockSpec((1, 1), lambda i, j: (i, 0),
-                               memory_space=pltpu.SMEM),
-                  pl.BlockSpec((1, BLOCK), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((1, BLOCK), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((r, npad), jnp.float32),
+        in_specs=[_SMEM, _TILE],
+        out_specs=_TILE,
+        out_shape=jax.ShapeDtypeStruct(qp.shape, jnp.float32),
         interpret=interpret,
-    )(scale.astype(jnp.float32).reshape(r, 1), qp)
-    return out[:, :n]
+    )(scale.astype(jnp.float32), qp)
+    return from_tiles(out, n)

@@ -37,18 +37,24 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, state_scr, *,
 
     x = x_ref[0].astype(jnp.float32)          # (cl, P)
     dt = dt_ref[0].astype(jnp.float32)        # (cl, 1)
-    a = a_ref[0, 0]                           # scalar decay rate (negative)
+    a = a_ref[pl.program_id(0)]               # scalar decay rate (negative)
     bmat = b_ref[0].astype(jnp.float32)       # (cl, N)
     cmat = c_ref[0].astype(jnp.float32)       # (cl, N)
 
     dA = dt * a                               # (cl, 1), negative
-    cum = jnp.cumsum(dA, axis=0)              # (cl, 1)
     xdt = x * dt                              # (cl, P)
 
-    # intra-chunk: L[i,j] = exp(cum_i - cum_j) for i >= j
+    # prefix sums as masked reductions (Mosaic has no cumsum):
+    # cum[i] = sum_{j<=i} dA[j], as a column (cl, 1) and a row (1, cl)
     li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(li >= lj, jnp.exp(cum - cum[:, 0][None, :]), 0.0)
+    dA_i = jnp.broadcast_to(dA, (chunk, chunk))       # [i, j] = dA[i]
+    cum = jnp.sum(jnp.where(li >= lj, dA_i.T, 0.0), axis=1, keepdims=True)
+    cum_row = jnp.sum(jnp.where(li <= lj, dA_i, 0.0), axis=0, keepdims=True)
+    total = jnp.sum(dA)
+
+    # intra-chunk: L[i,j] = exp(cum_i - cum_j) for i >= j
+    L = jnp.where(li >= lj, jnp.exp(cum - cum_row), 0.0)
     scores = jax.lax.dot_general(cmat, bmat, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
     y = jax.lax.dot_general(scores * L, xdt, (((1,), (0,)), ((), ())),
@@ -61,11 +67,11 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, state_scr, *,
                              preferred_element_type=jnp.float32)
 
     # state update: S_new = exp(total) S_prev + B^T-weighted inputs
-    decay_to_end = jnp.exp(cum[-1, 0] - cum)  # (cl, 1)
+    decay_to_end = jnp.exp(total - cum)       # (cl, 1)
     bw = bmat * decay_to_end
     s_chunk = jax.lax.dot_general(bw, xdt, (((0,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-    state_scr[...] = jnp.exp(cum[-1, 0]) * state + s_chunk
+    state_scr[...] = jnp.exp(total) * state + s_chunk
 
     o_ref[0] = y.astype(o_ref.dtype)
 
@@ -88,7 +94,7 @@ def ssd_scan_pallas(x, dt, A, Bm, Cm, *, chunk: int = 256,
     dtt = jnp.moveaxis(dt, 2, 1).reshape(BH, S, 1)
     bh = jnp.moveaxis(jnp.repeat(Bm, rep, axis=2), 2, 1).reshape(BH, S, N)
     ch = jnp.moveaxis(jnp.repeat(Cm, rep, axis=2), 2, 1).reshape(BH, S, N)
-    a_rates = jnp.tile(A.astype(jnp.float32), (Bsz,)).reshape(BH, 1)
+    a_rates = jnp.tile(A.astype(jnp.float32), (Bsz,))   # (BH,), in SMEM
 
     out = pl.pallas_call(
         functools.partial(_ssd_kernel, chunk=chunk),
@@ -96,7 +102,7 @@ def ssd_scan_pallas(x, dt, A, Bm, Cm, *, chunk: int = 256,
         in_specs=[
             pl.BlockSpec((1, chunk, P), lambda i, c: (i, c, 0)),
             pl.BlockSpec((1, chunk, 1), lambda i, c: (i, c, 0)),
-            pl.BlockSpec((1, 1), lambda i, c: (i, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, chunk, N), lambda i, c: (i, c, 0)),
             pl.BlockSpec((1, chunk, N), lambda i, c: (i, c, 0)),
         ],

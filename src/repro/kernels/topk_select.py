@@ -37,9 +37,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-BLOCK = 8 * 128 * 8  # 8192 elements per grid cell (f32 tile-aligned)
+SUBLANES, LANES = 8, 1024
+BLOCK = SUBLANES * LANES  # 8192 elements per grid cell: one (8, 1024) tile
 _BISECT_ITERS = 32
 _BIT_ITERS = 31      # int32 magnitude patterns are < 2^31: exact in 31
+
+
+# Each grid cell reads one (8, 1024) tile: the TPU lowering wants the last
+# two block dims divisible by (8, 128), so a BLOCK-long slice of the flat
+# vector is viewed as 8 sublane rows of 1024 lanes.  Slice i of the flat
+# vector is rows 8i..8i+7 of the (nblocks * 8, 1024) view.
+_TILE = pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0))
+# Per-call scalars live whole in SMEM, indexed by grid position.
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def to_tiles(x: jnp.ndarray):
+    """(..., N) -> zero-padded (..., nblocks * 8, 1024) tile view, nblocks."""
+    pad = (-x.shape[-1]) % BLOCK
+    xp = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+    nblocks = xp.shape[-1] // BLOCK
+    return xp.reshape(*x.shape[:-1], nblocks * SUBLANES, LANES), nblocks
+
+
+def from_tiles(xp: jnp.ndarray, n: int) -> jnp.ndarray:
+    """Inverse of :func:`to_tiles`: (..., nblocks * 8, 1024) -> (..., n)."""
+    return xp.reshape(*xp.shape[:-2], -1)[..., :n]
 
 
 def _topk_mask_kernel(x_ref, o_ref, *, k: int):
@@ -66,25 +89,22 @@ def topk_mask_pallas(x: jnp.ndarray, frac: float, *,
                      interpret: bool = True) -> jnp.ndarray:
     """x: flat (N,) -> bool mask keeping ~frac by block-local magnitude.
 
-    N is padded to a BLOCK multiple with -inf-magnitude ... actually zeros
-    (zeros never win a magnitude threshold > 0).
+    N is padded to a BLOCK multiple with zeros (zeros never win a
+    magnitude threshold > 0).
     """
     n = x.shape[0]
-    pad = (-n) % BLOCK
-    xp = jnp.pad(x, (0, pad))
-    nblocks = xp.shape[0] // BLOCK
-    xp = xp.reshape(nblocks, BLOCK)
+    xp, nblocks = to_tiles(x)
     k = max(int(BLOCK * frac), 1)
 
     out = pl.pallas_call(
         functools.partial(_topk_mask_kernel, k=k),
         grid=(nblocks,),
-        in_specs=[pl.BlockSpec((1, BLOCK), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nblocks, BLOCK), jnp.bool_),
+        in_specs=[_TILE],
+        out_specs=_TILE,
+        out_shape=jax.ShapeDtypeStruct(xp.shape, jnp.bool_),
         interpret=interpret,
     )(xp)
-    return out.reshape(-1)[:n]
+    return from_tiles(out, n)
 
 
 # ---------------------------------------------------------------------------
@@ -100,11 +120,11 @@ def _mag_bits(x: jnp.ndarray) -> jnp.ndarray:
 
 
 def _block_max_bits_kernel(x_ref, o_ref):
-    o_ref[0, 0] = jnp.max(_mag_bits(x_ref[...]))
+    o_ref[pl.program_id(0)] = jnp.max(_mag_bits(x_ref[...]))
 
 
 def _mask_ge_bits_kernel(t_ref, x_ref, o_ref):
-    o_ref[...] = _mag_bits(x_ref[...]) >= t_ref[0, 0]
+    o_ref[...] = _mag_bits(x_ref[...]) >= t_ref[0]
 
 
 def topk_mask_pallas_global(x: jnp.ndarray, frac: float, *,
@@ -114,20 +134,18 @@ def topk_mask_pallas_global(x: jnp.ndarray, frac: float, *,
     k = max(int(N * frac), 1) — bit-identical to the jax.lax.top_k oracle.
     """
     n = x.shape[0]
-    pad = (-n) % BLOCK
-    xp = jnp.pad(x, (0, pad))          # zero padding: bits == 0, and the
-    nblocks = xp.shape[0] // BLOCK     # bisection only counts bits >= mid
-    xp = xp.reshape(nblocks, BLOCK)    # with mid >= 1, so pads never count
+    # zero padding: bits == 0, and the bisection only counts bits >= mid
+    # with mid >= 1, so pads never count
+    xp, nblocks = to_tiles(x)
     k = max(int(n * frac), 1)
 
     # pass 1: per-block maxima of the bit-cast magnitudes
     bmax = pl.pallas_call(
         _block_max_bits_kernel,
         grid=(nblocks,),
-        in_specs=[pl.BlockSpec((1, BLOCK), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0),
-                               memory_space=pltpu.SMEM),
-        out_shape=jax.ShapeDtypeStruct((nblocks, 1), jnp.int32),
+        in_specs=[_TILE],
+        out_specs=_SMEM,
+        out_shape=jax.ShapeDtypeStruct((nblocks,), jnp.int32),
         interpret=interpret,
     )(xp)
 
@@ -152,11 +170,9 @@ def topk_mask_pallas_global(x: jnp.ndarray, frac: float, *,
     out = pl.pallas_call(
         _mask_ge_bits_kernel,
         grid=(nblocks,),
-        in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0),
-                               memory_space=pltpu.SMEM),
-                  pl.BlockSpec((1, BLOCK), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nblocks, BLOCK), jnp.bool_),
+        in_specs=[_SMEM, _TILE],
+        out_specs=_TILE,
+        out_shape=jax.ShapeDtypeStruct(xp.shape, jnp.bool_),
         interpret=interpret,
-    )(t.reshape(1, 1), xp)
-    return out.reshape(-1)[:n]
+    )(t.reshape(1), xp)
+    return from_tiles(out, n)

@@ -13,17 +13,8 @@ this module never touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5 has explicit axis types; older jax is Auto-only
-    from jax.sharding import AxisType
-
-    def _axis_kw(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
-
-    def _axis_kw(n: int) -> dict:
-        return {}
 
 # v5e hardware constants (roofline denominators; see roofline/analysis.py)
 PEAK_FLOPS_BF16 = 197e12      # per chip
@@ -34,16 +25,19 @@ ICI_BW = 50e9                 # bytes/s per link (≈2 usable links per axis)
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kw(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever local devices exist (tests / smoke)."""
     n = len(jax.devices())
     assert data * model <= n, (data, model, n)
-    return jax.make_mesh((data, model), ("data", "model"), **_axis_kw(2))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_users_mesh(num_users: int):
     """Federation mesh for the SPMD Distributed-GAN (one user per slice)."""
-    return jax.make_mesh((num_users,), ("users",), **_axis_kw(1))
+    return jax.make_mesh((num_users,), ("users",),
+                         axis_types=(AxisType.Auto,))

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import get_config
 from repro.data.synthetic import synthetic_batch_for
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.models.cache import cache_nbytes  # noqa: F401  (re-export)
 
@@ -51,6 +52,7 @@ def greedy_decode(cfg, params, prompt, gen_len: int, *, src_embeds=None):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")

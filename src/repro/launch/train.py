@@ -21,6 +21,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from repro.checkpoint import latest_step, restore_checkpoint, save_checkpoint
 from repro.configs.base import INPUT_SHAPES, get_config
 from repro.data.synthetic import TokenStream, synthetic_batch_for
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_train_step, param_pspecs
 from repro.models import model as M
@@ -28,6 +29,7 @@ from repro.optim import cosine_schedule
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")

@@ -112,8 +112,6 @@ def roofline_terms(*, arch: str, shape: str, mesh_name: str, chips: int,
     """cost: compiled.cost_analysis() dict.  NOTE on conventions: XLA's
     cost analysis reports the per-partition program; we treat `flops` and
     `bytes accessed` as per-chip numbers for the SPMD program."""
-    if isinstance(cost, (list, tuple)):   # jax 0.4.x wraps it in a list
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     coll = float(collective.get("total", 0.0))

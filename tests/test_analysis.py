@@ -123,8 +123,6 @@ def test_tracecheck_flags_missing_scan_and_barriers():
 
 
 def test_tracecheck_flags_float64_conversion():
-    from jax.experimental import enable_x64
-
     def promote(x):
         return jax.lax.convert_element_type(x, jnp.float64)
 
@@ -135,7 +133,7 @@ def test_tracecheck_flags_float64_conversion():
         donate=(), min_barriers=0, expect_scan=False)
     # the promotion only materializes under x64 — exactly the implicit
     # weak-type blowup TRC003 exists to catch
-    with enable_x64():
+    with jax.enable_x64():
         assert "TRC003" in {v.rule for v in check_specimen(sp)}
 
 

@@ -111,14 +111,13 @@ def test_spmd_combine_matches_host_combine():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as PS
         from repro.core.federated import combine_max_abs, combine_max_abs_spmd
-        from repro.core.spmd import shard_map_compat
         from repro.launch.mesh import make_users_mesh
         mesh = make_users_mesh(4)
         d = jax.random.normal(jax.random.key(0), (4, 37))
         def body(x):
             return combine_max_abs_spmd({"w": x[0]}, "users")["w"]
-        out = jax.jit(shard_map_compat(body, mesh, in_specs=PS("users"),
-                                       out_specs=PS()))(d)
+        out = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=PS("users"),
+                                    out_specs=PS(), check_vma=False))(d)
         want = combine_max_abs({"w": d})["w"]
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    rtol=1e-6)

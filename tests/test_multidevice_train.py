@@ -24,13 +24,9 @@ def test_sharded_training_runs_and_matches_single_device():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, numpy as np
-        # ROOT CAUSE of the pre-existing GSPMD "numerics" failure on the
-        # jax 0.4.x line: threefry is NOT partitionable by default there,
-        # so jax.random under out_shardings generates DIFFERENT bits for
-        # sharded outputs (embed/unembed were entirely different arrays,
-        # not ULP noise) and the two runs never start from the same
-        # params.  Partition-invariant threefry (the default on newer
-        # jax) makes init identical; the trajectories then agree to
+        # partition-invariant threefry: jax.random under out_shardings
+        # generates the same bits for sharded outputs, so both runs
+        # start from the same params; the trajectories then agree to
         # ~2e-4, comfortably inside the 2e-3 assertion.
         jax.config.update("jax_threefry_partitionable", True)
         from jax.sharding import NamedSharding, PartitionSpec

@@ -98,7 +98,6 @@ def test_shared_random_sync_preserves_unselected():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as PS
         from repro.core.federated import combine_shared_random_spmd
-        from repro.core.spmd import shard_map_compat
         from repro.launch.mesh import make_users_mesh
         mesh = make_users_mesh(2)
         d = jax.random.normal(jax.random.key(0), (2, 100))
@@ -107,8 +106,8 @@ def test_shared_random_sync_preserves_unselected():
             out, kept = combine_shared_random_spmd({"w": x[0]}, 0.2, key,
                                                    "users")
             return out["w"], kept
-        out, kept = jax.jit(shard_map_compat(
-            body, mesh, in_specs=PS("users"),
+        out, kept = jax.jit(jax.shard_map(
+            body, mesh=mesh, check_vma=False, in_specs=PS("users"),
             out_specs=(PS(), PS())))(d)
         out = np.asarray(out)
         mean = np.asarray(d.mean(0))

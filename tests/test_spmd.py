@@ -94,13 +94,12 @@ def test_spmd_approach2_grad_matches_host_simulation():
             # per-shard grads are complete; pmean just de-duplicates
             return jax.tree.map(lambda x: jax.lax.pmean(x, "users"), grads)
 
-        from repro.core.spmd import shard_map_compat
-        got = jax.jit(shard_map_compat(
-            body, mesh,
+        got = jax.jit(jax.shard_map(
+            body, mesh=mesh, check_vma=False,
             in_specs=(jax.tree.map(lambda _: PS(), g),
                       jax.tree.map(lambda _: PS("users"), ds)),
             out_specs=jax.tree.map(lambda _: PS(), g)))(g, ds)
-        # GSPMD on the jax 0.4.x line lowers the cotangent psum to an
+        # GSPMD lowers the cotangent psum to an
         # all-reduce whose accumulation order differs from the host vmap's
         # fused reduction.  Where per-user contributions cancel, the
         # absolute error scales with the SUMMANDS' magnitude, not the

@@ -104,6 +104,7 @@ def init_state(pair, fcfg: DistGANConfig, key, *,
 def _d_update_fn(pair, d_opt_def, fcfg: DistGANConfig | None = None):
     wgan = fcfg is not None and fcfg.loss_type == "wgan"
 
+    @jax.named_scope("fed.d_update")
     def one(d, opt, real, fake):
         def loss_fn(dp):
             rs, fs = pair.d_apply(dp, real), pair.d_apply(dp, fake)
@@ -138,6 +139,7 @@ def _pin(*trees):
     return out[0] if len(trees) == 1 else out
 
 
+@jax.named_scope("fed.g_update")
 def _g_update(pair, g_opt_def, state, loss_fn):
     loss, grads = jax.value_and_grad(loss_fn)(state.g)
     grads = _pin(grads)
@@ -203,7 +205,8 @@ def make_approach1_body(pair, fcfg: DistGANConfig):
             key, kz1, kz2, ksel = jax.random.split(state.key, 4)
         B = real.shape[1]
         U = real.shape[0]
-        fake = pair.g_apply(state.g, pair.sample_z(kz1, B))
+        with jax.named_scope("fed.fakes"):
+            fake = pair.g_apply(state.g, pair.sample_z(kz1, B))
 
         old_flat = layout.flatten_stacked(state.ds)        # (C, N)
         ds, d_opts, d_losses = _pin(*jax.vmap(
@@ -245,12 +248,16 @@ def make_approach1_body(pair, fcfg: DistGANConfig):
             # member's upload BEFORE the fold (weights are normalized to
             # mean 1 host-side, so server_scale semantics are preserved)
             masked = masked * weights[:, None]
-        if getattr(combiner, "needs_ages", False):
-            combined = combiner(masked, ages, decay=fcfg.staleness_decay)
-        else:
-            combined = combiner(masked)                    # (N,)
-        server_flat = (layout.flatten(state.server_d)
-                       + fcfg.server_scale * combined)
+        # the fold: the combiner over the uploaded rows, and the add of
+        # what it returns into the server row
+        with jax.named_scope("fed.fold"):
+            if getattr(combiner, "needs_ages", False):
+                combined = combiner(masked, ages,
+                                    decay=fcfg.staleness_decay)
+            else:
+                combined = combiner(masked)                # (N,)
+            server_flat = (layout.flatten(state.server_d)
+                           + fcfg.server_scale * combined)
         server_d = layout.unflatten(server_flat)
 
         # download phase (paper §3.1: "users update local model with the

@@ -76,7 +76,9 @@ def _masked(body):
         xs, valid = inp
         new_carry, metrics = body(carry, xs)
         keep = lambda n, o: jnp.where(valid, n, o)
-        return jax.tree.map(keep, new_carry, carry), metrics
+        with jax.named_scope("fed.window_mask"):
+            new_carry = jax.tree.map(keep, new_carry, carry)
+        return new_carry, metrics
 
     return wrapped
 
@@ -202,7 +204,11 @@ def _cohort_round_fn(pair, fcfg: DistGANConfig, approach: str) -> Callable:
         real, idx, *rest = inp
         w = rest[0] if rest else None
         store = carry.store
-        ds, opts = cohort_gather(store, idx, d_layout, o_layout)
+        with jax.named_scope("fed.store_gather"):
+            ds, opts = cohort_gather(store, idx, d_layout, o_layout)
+            # error-feedback rows ride the same gather/scatter as the D
+            # rows: user-local state, visible only to its own rounds
+            res = store.residual[idx] if ef else None
         # materialize the gathered slices: without the barrier XLA may fuse
         # the gather/unflatten into the body's loss reductions and change
         # their tiling, breaking ULP-equality with the non-virtualized
@@ -212,10 +218,7 @@ def _cohort_round_fn(pair, fcfg: DistGANConfig, approach: str) -> Callable:
         state = DistGANState(carry.g, carry.g_opt, ds, opts, carry.server_d,
                              carry.step, carry.key)
         if ef:
-            # error-feedback rows ride the same gather/scatter as the D
-            # rows: user-local state, visible only to its own rounds
-            new_state, metrics, new_res = body(state, real, ages, w,
-                                               store.residual[idx])
+            new_state, metrics, new_res = body(state, real, ages, w, res)
         else:
             new_state, metrics = body(state, real, ages, w)
             new_res = None
@@ -228,9 +231,10 @@ def _cohort_round_fn(pair, fcfg: DistGANConfig, approach: str) -> Callable:
         # carries age step - last_round == 0 — the re-zeroed age
         # convention (fresh folds are no longer uniformly discounted by
         # one decay factor by the staleness combiners)
-        store = cohort_scatter(store, idx, nds, nopts,
-                               carry.step + 1, d_layout, o_layout,
-                               residual=new_res)
+        with jax.named_scope("fed.store_scatter"):
+            store = cohort_scatter(store, idx, nds, nopts,
+                                   carry.step + 1, d_layout, o_layout,
+                                   residual=new_res)
         new_carry = CohortState(new_state.g, new_state.g_opt, store,
                                 new_state.server_d, new_state.step,
                                 new_state.key)

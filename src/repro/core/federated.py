@@ -541,6 +541,7 @@ def random_mask(flat: jnp.ndarray, frac: float, key) -> jnp.ndarray:
     return jax.random.uniform(key, flat.shape) < frac
 
 
+@jax.named_scope("fed.select")
 def select_delta_flat(flat: jnp.ndarray, policy: Selection, *, frac=0.1,
                       tau=0.0, key=None, use_kernel: bool = False):
     """Apply a selection policy to one flat (N,) delta buffer.
@@ -593,6 +594,7 @@ def select_delta(delta_tree, policy: Selection, *, frac=0.1, tau=0.0,
 # feedback residual (compensated - transported) is computed by the
 # callers (approaches/spmd), because only they know the compensation.
 
+@jax.named_scope("fed.codec")
 def codec_transport(rows: jnp.ndarray, codec: str, *,
                     stochastic: bool = False, seed=None,
                     use_kernel: bool = False) -> jnp.ndarray:
@@ -750,6 +752,7 @@ COMBINERS = COMBINER_REGISTRY.entries
 # SPMD combination (inside shard_map, one user per 'users' axis slice)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("fed.fold")
 def combine_max_abs_spmd(delta_tree, axis: str = "users"):
     """Paper's max-|.| rule as collectives: pmax of |delta|, then each user
     contributes its delta only where it attains the max; psum-normalized
@@ -765,6 +768,7 @@ def combine_max_abs_spmd(delta_tree, axis: str = "users"):
     return jax.tree.map(one, delta_tree)
 
 
+@jax.named_scope("fed.fold")
 def combine_mean_spmd(delta_tree, axis: str = "users"):
     return jax.tree.map(lambda d: jax.lax.pmean(d, axis), delta_tree)
 
@@ -785,6 +789,7 @@ def combine_shared_random_spmd(delta_tree, frac: float, key,
     return unravel(out), kept
 
 
+@jax.named_scope("fed.fold")
 def combine_shared_random_flat_spmd(flat: jnp.ndarray, frac: float, key,
                                     axis: str = "users"):
     """Flat-buffer core of ``combine_shared_random_spmd``: the engine calls

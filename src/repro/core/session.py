@@ -71,12 +71,15 @@ _STAGE_CAP_BYTES = 256 * 1024 * 1024
 
 _SESSION_META = "session.json"
 
+# JAX's own duration event for one backend compilation (or a persistent
+# cache read standing in for one)
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
 
 @dataclasses.dataclass
 class RunResult:
     g_losses: np.ndarray           # (steps,)
     d_losses: np.ndarray           # (steps, U) — (steps, C) under cohorting
-    wall_time_s: float
     step_time_s: float             # steady-state per-step (post-compile)
     samples: np.ndarray | None
     state: typing.Any              # DistGANState | None
@@ -106,7 +109,6 @@ def _merge_results(parts: list) -> RunResult:
     return RunResult(
         g_losses=np.concatenate([p.g_losses for p in parts]),
         d_losses=np.concatenate([p.d_losses for p in parts]),
-        wall_time_s=sum(p.wall_time_s for p in parts),
         step_time_s=parts[-1].step_time_s,
         samples=parts[-1].samples,
         state=parts[-1].state,
@@ -128,12 +130,18 @@ def _chunk_slice(staged, start: int, k: int, rpj: int):
     return out
 
 
-def _chunk_stack(batch_fn, start: int, k: int, rpj: int):
+def _chunk_stack(batch_fn, start: int, k: int, rpj: int, window: int):
     """Host-side chunk: sample rounds ``[start, start+k)``, pad to rpj
-    (same repeat-the-last-round convention as engine._pad_to)."""
-    block = _pad_to(np.stack([batch_fn(j) for j in range(start, start + k)]),
-                    rpj)
-    return jnp.asarray(block)
+    (same repeat-the-last-round convention as engine._pad_to), and copy
+    it to the device.  ``window`` (the session round at the window's
+    start) tags the ``fed.sample`` and ``fed.h2d`` spans."""
+    with jax.profiler.TraceAnnotation("fed.sample", window=window) as span:
+        block = _pad_to(np.stack([batch_fn(j)
+                                  for j in range(start, start + k)]), rpj)
+        span.set_metadata(bytes=block.nbytes)
+    with jax.profiler.TraceAnnotation("fed.h2d", window=window,
+                                      bytes=block.nbytes):
+        return jnp.asarray(block)
 
 
 def _valid_mask(k: int, rpj: int):
@@ -154,7 +162,7 @@ def _poison_donated(tree) -> None:
             leaf.delete()
 
 
-def _drive_chunks(run_chunk, carry, steps: int, rpj: int,
+def _drive_chunks(run_chunk, carry, steps: int, rpj: int, window: int,
                   donating: bool = False):
     """Warmup + timed chunk loop shared by the fused and cohort drivers.
 
@@ -168,18 +176,26 @@ def _drive_chunks(run_chunk, carry, steps: int, rpj: int,
     the carry to its engine: each consumed carry is then poisoned
     (:func:`_poison_donated`) so any stale reference held elsewhere —
     the driver's own ``_state`` mid-run included — raises immediately
-    instead of reading a pre-window copy.  Returns ``(carry, chunks,
-    compile_s, steady_s, window_rates)``; ``window_rates`` holds
-    per-round seconds of each FULL post-warmup window — the remainder
-    window is excluded because its rate would over-count the masked
-    padding rounds it still computes."""
+    instead of reading a pre-window copy.  ``run_chunk`` returns the
+    chunk's metrics on the device; each chunk ends in one host sync on
+    them (the ``fed.sync`` span, tagged with ``window``), with the padded
+    rounds sliced off.  Returns ``(carry, chunks, steady_s,
+    window_rates)``; ``window_rates`` holds per-round seconds of each
+    FULL post-warmup window — the remainder window is excluded because
+    its rate would over-count the masked padding rounds it still
+    computes."""
+
+    def chunk(start: int, k: int, carry):
+        prev = carry
+        carry, m = run_chunk(start, k, carry)
+        with jax.profiler.TraceAnnotation("fed.sync", window=window):
+            m = jax.tree.map(lambda x: np.asarray(x)[:k], m)
+        if donating:
+            _poison_donated(prev)
+        return carry, m
+
     k0 = min(rpj, steps)
-    t0 = time.perf_counter()
-    prev = carry
-    carry, m0 = run_chunk(0, k0, carry)
-    if donating:
-        _poison_donated(prev)
-    compile_s = time.perf_counter() - t0
+    carry, m0 = chunk(0, k0, carry)
     chunks = [m0]
 
     t1 = time.perf_counter()
@@ -188,17 +204,14 @@ def _drive_chunks(run_chunk, carry, steps: int, rpj: int,
     while i < steps:
         k = min(rpj, steps - i)
         tc = time.perf_counter()
-        prev = carry
-        carry, m = run_chunk(i, k, carry)
-        if donating:
-            _poison_donated(prev)
+        carry, m = chunk(i, k, carry)
         if k == rpj:
             window_rates.append((time.perf_counter() - tc) / k)
         chunks.append(m)
         i += k
     jax.block_until_ready(carry.g)
     steady = time.perf_counter() - t1
-    return carry, chunks, compile_s, steady, window_rates
+    return carry, chunks, steady, window_rates
 
 
 def _upload_accounting(pair, fcfg: DistGANConfig, approach, C: int,
@@ -485,7 +498,7 @@ def superbatch_cohort_rounds(eng, shared, backend, schedule: np.ndarray,
                 [np.asarray(backend.gather_residual(schedule[i + r]))
                  for r in range(k)]), rpj)
         if data is None:
-            data = _chunk_stack(batch_fn, i, k, rpj)
+            data = _chunk_stack(batch_fn, i, k, rpj, round_base)
         w = None
         if wts is not None:
             w = jnp.asarray(_pad_to(np.asarray(wts[i:i + k], np.float32),
@@ -507,7 +520,7 @@ def superbatch_cohort_rounds(eng, shared, backend, schedule: np.ndarray,
         data = None
         if prefetch and i + k < steps:
             kn = min(rpj, steps - i - k)
-            data = _chunk_stack(batch_fn, i + k, kn, rpj)
+            data = _chunk_stack(batch_fn, i + k, kn, rpj, round_base)
         t0 = time.perf_counter()
         out_d, out_o = np.asarray(out_d), np.asarray(out_o)  # THE stall
         if out_r is not None:
@@ -685,11 +698,13 @@ class DeviceBackendDriver(BackendDriver):
     # -- execution ---------------------------------------------------------
 
     def run(self, rounds: int) -> RunResult:
-        if self.mode == "cohort":
-            return self._run_cohort(rounds)
-        if self.mode == "fused":
+        if self.mode == "per_step":
+            return self._run_per_step(rounds)
+        with jax.profiler.TraceAnnotation("fed.run", window=self.sess.round,
+                                          rounds=rounds):
+            if self.mode == "cohort":
+                return self._run_cohort(rounds)
             return self._run_fused(rounds)
-        return self._run_per_step(rounds)
 
     def _window_rpj(self, rounds: int) -> int:
         # ALWAYS the spec's chunk length, independent of the window size
@@ -702,24 +717,23 @@ class DeviceBackendDriver(BackendDriver):
 
     def _run_fused(self, rounds: int) -> RunResult:
         sess = self.sess
+        window = sess.round
         rpj = self._window_rpj(rounds)
-        batch_np = sess._batch_full
+        batch_np = lambda j: sess._batch_full()
         prestage = rounds * sess._probe_nbytes_full() <= _STAGE_CAP_BYTES
         if prestage:
-            staged = jnp.asarray(np.stack([batch_np()
-                                           for _ in range(rounds)]))
+            staged = _chunk_stack(batch_np, 0, rounds, rounds, window)
 
         def run_chunk(start: int, k: int, state):
             reals = (_chunk_slice(staged, start, k, rpj) if prestage
-                     else _chunk_stack(lambda j: batch_np(), start, k, rpj))
-            state, m = self.eng(state, reals, _valid_mask(k, rpj))
-            # one sync per chunk; padded rounds sliced off
-            return state, jax.tree.map(lambda x: np.asarray(x)[:k], m)
+                     else _chunk_stack(batch_np, start, k, rpj, window))
+            with jax.profiler.TraceAnnotation("fed.dispatch", window=window):
+                return self.eng(state, reals, _valid_mask(k, rpj))
 
         # make_engine donates the state carry (argnum 0): poison each
         # consumed window carry so a stale self._state read fails fast
-        state, chunks, compile_s, steady, window_rates = _drive_chunks(
-            run_chunk, self.state, rounds, rpj, donating=True)
+        state, chunks, steady, window_rates = _drive_chunks(
+            run_chunk, self.state, rounds, rpj, window, donating=True)
         self.state = state
 
         g_losses = np.concatenate([c["g_loss"] for c in chunks])
@@ -733,11 +747,10 @@ class DeviceBackendDriver(BackendDriver):
         return RunResult(
             g_losses=g_losses,
             d_losses=d_losses,
-            wall_time_s=compile_s + steady,
             step_time_s=steady / step_denom,
             samples=sess._eval_samples(state.g),
             state=state,
-            extra={"compile_s": compile_s, "kept_frac": kept_frac,
+            extra={"kept_frac": kept_frac,
                    "engine": "fused",
                    # best post-warmup window: steady-state per-round
                    # time, robust to background load spikes (benchmarks
@@ -761,10 +774,8 @@ class DeviceBackendDriver(BackendDriver):
             return b
 
         # warmup/compile on the window's first shapes
-        t0 = time.perf_counter()
         state, metrics = self.step_fn(state, batch())
         jax.block_until_ready(metrics["g_loss"])
-        compile_s = time.perf_counter() - t0
 
         g_list.append(float(metrics["g_loss"]))
         d_list.append(np.asarray(metrics["d_loss"]))
@@ -789,11 +800,10 @@ class DeviceBackendDriver(BackendDriver):
         return RunResult(
             g_losses=np.asarray(g_list),
             d_losses=np.stack(d_list),
-            wall_time_s=compile_s + steady,
             step_time_s=steady / step_denom,
             samples=sess._eval_samples(state.g),
             state=state,
-            extra={"compile_s": compile_s, "kept_frac": kept_frac,
+            extra={"kept_frac": kept_frac,
                    "engine": "per_step",
                    "min_step_time_s": min_step_s,
                    **_upload_accounting(sess.pair, sess.fcfg,
@@ -806,6 +816,7 @@ class DeviceBackendDriver(BackendDriver):
         program (see FederationSession._next_schedule for the rng-stream
         discipline)."""
         sess = self.sess
+        window = sess.round
         U, C = sess.fcfg.num_users, sess.cohort_size
         schedule = sess._next_schedule(rounds)
         wts = sess._next_weights(schedule)
@@ -820,25 +831,24 @@ class DeviceBackendDriver(BackendDriver):
         nbytes = sess._probe_nbytes_cohort(schedule)
         prestage = rounds * nbytes <= _STAGE_CAP_BYTES
         if prestage:
-            staged = jnp.asarray(np.stack([batch_round(j)
-                                           for j in range(rounds)]))
+            staged = _chunk_stack(batch_round, 0, rounds, rounds, window)
         sched_dev = jnp.asarray(schedule)
         wts_dev = None if wts is None else jnp.asarray(wts)
 
         def run_chunk(start: int, k: int, cstate):
             reals = (_chunk_slice(staged, start, k, rpj) if prestage
-                     else _chunk_stack(batch_round, start, k, rpj))
+                     else _chunk_stack(batch_round, start, k, rpj, window))
             idx = _chunk_slice(sched_dev, start, k, rpj)
             w = (None if wts_dev is None
                  else _chunk_slice(wts_dev, start, k, rpj))
-            cstate, m = self.eng(cstate, reals, idx, wts=w,
-                                 valid=_valid_mask(k, rpj))
-            return cstate, jax.tree.map(lambda x: np.asarray(x)[:k], m)
+            with jax.profiler.TraceAnnotation("fed.dispatch", window=window):
+                return self.eng(cstate, reals, idx, wts=w,
+                                valid=_valid_mask(k, rpj))
 
         # only the fused-store engine donates the carry (the plain cohort
         # engine keeps the bitwise-pin copy — its carry stays readable)
-        cstate, chunks, compile_s, steady, window_rates = _drive_chunks(
-            run_chunk, self.cstate, rounds, rpj,
+        cstate, chunks, steady, window_rates = _drive_chunks(
+            run_chunk, self.cstate, rounds, rpj, window,
             donating=self.fused_store)
         self.cstate = cstate
 
@@ -854,14 +864,15 @@ class DeviceBackendDriver(BackendDriver):
         counts = np.bincount(schedule.ravel(), minlength=U)
         total = sess.round + rounds
         staleness = total - np.asarray(cstate.store.last_round)
+        with jax.profiler.TraceAnnotation("fed.unpack", window=window):
+            state = cohort_state_to_full(sess.pair, sess.fcfg, cstate)
         return RunResult(
             g_losses=g_losses,
             d_losses=d_losses,
-            wall_time_s=compile_s + steady,
             step_time_s=steady / step_denom,
             samples=sess._eval_samples(cstate.g),
-            state=cohort_state_to_full(sess.pair, sess.fcfg, cstate),
-            extra={"compile_s": compile_s, "kept_frac": kept_frac,
+            state=state,
+            extra={"kept_frac": kept_frac,
                    "engine": "fused", "min_step_time_s": min_step_s,
                    "participation": sess.spec.participation.scheduler,
                    "cohort_size": C,
@@ -1008,7 +1019,6 @@ class HostStreamDriver(BackendDriver):
                                         sp.batch_size))
                 for u in schedule[r]])
 
-        t0 = time.perf_counter()
         if self.fused_store:
             rpj = sp.engine.rounds_per_jit
             self.shared, mets, wstats = superbatch_cohort_rounds(
@@ -1020,7 +1030,6 @@ class HostStreamDriver(BackendDriver):
             # the per-round stall is the window's single block divided by
             # its real rounds
             wr = wstats.win_retire_t
-            compile_s = wr[0] - t0
             steady = wr[-1] - wr[0] if len(wr) > 1 else 0.0
             step_denom = max(rounds - wstats.win_rounds[0], 1)
             rates = [(wr[j] - wr[j - 1]) / wstats.win_rounds[j]
@@ -1040,7 +1049,6 @@ class HostStreamDriver(BackendDriver):
                 stage_codec="int8" if self.stage_rows else "none")
 
             retire_t = stats.retire_t
-            compile_s = retire_t[0] - t0
             steady = retire_t[-1] - retire_t[0] if rounds > 1 else 0.0
             step_denom = max(rounds - 1, 1)
             # steady-state per-round estimate: min over sliding windows
@@ -1084,11 +1092,10 @@ class HostStreamDriver(BackendDriver):
         return RunResult(
             g_losses=g_losses,
             d_losses=d_losses,
-            wall_time_s=compile_s + steady,
             step_time_s=steady / step_denom,
             samples=sess._eval_samples(self.shared.g),
             state=state,
-            extra={"compile_s": compile_s, "kept_frac": kept_frac,
+            extra={"kept_frac": kept_frac,
                    "engine": "fused", "min_step_time_s": min_step_s,
                    "participation": sp.participation.scheduler,
                    "cohort_size": C,
@@ -1119,6 +1126,27 @@ register_backend("host", HostStreamDriver, streams=True)
 # ---------------------------------------------------------------------------
 # The session
 # ---------------------------------------------------------------------------
+
+class _CompileClock:
+    """Seconds JAX spends in backend compilation while the clock is open,
+    from JAX's own monitoring events: 0.0 for a window that compiled
+    nothing.  The listener is process-wide, so a compile on another
+    thread in the same stretch counts too."""
+
+    def __init__(self):
+        self.seconds = 0.0
+
+    def _listen(self, event: str, duration_secs: float, **_) -> None:
+        if event == _COMPILE_EVENT:
+            self.seconds += duration_secs
+
+    def __enter__(self) -> "_CompileClock":
+        jax.monitoring.register_event_duration_secs_listener(self._listen)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        jax.monitoring.unregister_event_duration_listener(self._listen)
+
 
 class FederationSession:
     """Resumable, incrementally-driven federation run described by a
@@ -1329,7 +1357,9 @@ class FederationSession:
                     eval_samples: int | None) -> RunResult:
         self._eval_override = eval_samples
         self._mid_window = True
-        result = self._driver.run(rounds)
+        with _CompileClock() as compiles:
+            result = self._driver.run(rounds)
+        result.extra["compile_s"] = compiles.seconds
         # only on success: a mid-window failure leaves rng streams /
         # counts / carry partially advanced, and save() must refuse
         self._mid_window = False

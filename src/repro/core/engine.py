@@ -18,13 +18,17 @@ PRNG folding goes through ``state.key`` exactly as in the per-step path,
 so the scanned trajectory is bit-identical to the Python loop (pinned by
 tests/test_engine.py).
 
-Every engine takes an optional ``valid (K,) bool`` third argument: rounds
+Every engine takes an optional ``valid (K,) bool`` argument: rounds
 flagged invalid leave the carry untouched (their metrics are garbage and
 must be sliced off by the caller).  ``run_scanned`` uses this to pad the
 trailing remainder chunk to a full ``rounds_per_jit`` rounds, so ANY
 ``steps % rounds_per_jit`` compiles exactly one program.  A valid round's
 update is a ``jnp.where(True, new, old)`` — an exact select, so masking
-never perturbs trajectories.
+never perturbs trajectories.  Engines whose carry is rewritten whole
+every round select over the whole carry (``_masked``); the store-resident
+cohort engines mask at row granularity instead: an invalid round writes
+its C gathered rows back unchanged and selects only the small replicated
+leaves, so no round touches more of the (U, N) store than its C rows.
 
 Cohort virtualization (``make_cohort_engine``): a run can have U LOGICAL
 users while the compiled program is shaped only by a cohort width C <= U.
@@ -63,14 +67,18 @@ from repro.core.approaches import (DistGANConfig, DistGANState, _opts,
                                    init_state)
 from repro.core.federated import (CohortStore, HostStateBackend,
                                   cohort_gather, cohort_scatter,
-                                  make_cohort_store)
+                                  make_cohort_store, take_rows)
 from repro.core.spec import DEFAULT_ROUNDS_PER_JIT, resolve_approach
 
 
 def _masked(body):
     """Wrap a scan body so rounds with ``valid=False`` leave the carry
-    untouched.  ``jnp.where`` on a scalar bool is an exact select: with
-    ``valid=True`` the output is bitwise the unmasked result."""
+    untouched, by a select over EVERY carry leaf.  ``jnp.where`` on a
+    scalar bool is an exact select: with ``valid=True`` the output is
+    bitwise the unmasked result.  For carries the body rewrites whole
+    every round; the cohort store engines mask their C rows instead
+    (``_cohort_round_fn``), since a whole-carry select there is a pass
+    over the (U, N) store each round."""
 
     def wrapped(carry, inp):
         xs, valid = inp
@@ -188,11 +196,19 @@ def cohort_state_to_full(pair, fcfg: DistGANConfig,
 
 
 def _cohort_round_fn(pair, fcfg: DistGANConfig, approach: str) -> Callable:
-    """One store-resident cohort round: gather the scheduled rows, run the
+    """One store-resident cohort round: read the scheduled rows, run the
     width-C body, scatter the updated rows back (stamping ``last_round``).
     Shared by ``make_cohort_engine`` and ``make_fused_store_engine`` —
     the two jits trace the IDENTICAL program and differ only in carry
-    donation."""
+    donation.
+
+    ``inp`` is ``(real, idx, w, valid)``; ``w`` and ``valid`` may be None.
+    With ``valid`` the round masks at row granularity: an invalid round
+    scatters the C rows it read back unchanged (every duplicate index
+    writes the same old row), stamps ``last_round`` with its old value,
+    and keeps the replicated leaves — bitwise a no-op on the store, while
+    a valid round's rows are the selected ones bitwise.  Every per-round
+    access to the (U, N) buffers is thus a C-row access."""
     appr = resolve_approach(approach)
     assert appr.user_axis, f"{approach} has no user axis to virtualize"
     body = appr.body_factory(pair, fcfg)
@@ -201,14 +217,15 @@ def _cohort_round_fn(pair, fcfg: DistGANConfig, approach: str) -> Callable:
     ef = _wants_residual(fcfg)
 
     def round_fn(carry: CohortState, inp):
-        real, idx, *rest = inp
-        w = rest[0] if rest else None
+        real, idx, w, valid = inp
         store = carry.store
         with jax.named_scope("fed.store_gather"):
-            ds, opts = cohort_gather(store, idx, d_layout, o_layout)
-            # error-feedback rows ride the same gather/scatter as the D
-            # rows: user-local state, visible only to its own rounds
-            res = store.residual[idx] if ef else None
+            # C row slices, not ``cohort_gather``'s XLA gather (see
+            # take_rows); error-feedback rows ride the same read/scatter
+            # as the D rows: user-local state, visible only to its rounds
+            ds = d_layout.unflatten_stacked(take_rows(store.d_flat, idx))
+            opts = o_layout.unflatten_stacked(take_rows(store.opt_flat, idx))
+            res = take_rows(store.residual, idx) if ef else None
         # materialize the gathered slices: without the barrier XLA may fuse
         # the gather/unflatten into the body's loss reductions and change
         # their tiling, breaking ULP-equality with the non-virtualized
@@ -231,17 +248,39 @@ def _cohort_round_fn(pair, fcfg: DistGANConfig, approach: str) -> Callable:
         # carries age step - last_round == 0 — the re-zeroed age
         # convention (fresh folds are no longer uniformly discounted by
         # one decay factor by the staleness combiners)
+        stamp = carry.step + 1
+        shared = (new_state.g, new_state.g_opt, new_state.server_d,
+                  new_state.step, new_state.key)
+        if valid is not None:
+            with jax.named_scope("fed.window_mask"):
+                keep = lambda n, o: jnp.where(valid, n, o)
+                nds, nopts, new_res = jax.tree.map(
+                    keep, (nds, nopts, new_res), (ds, opts, res))
+                stamp = jnp.where(valid, stamp, store.last_round[idx])
+                shared = jax.tree.map(keep, shared, (
+                    carry.g, carry.g_opt, carry.server_d, carry.step,
+                    carry.key))
         with jax.named_scope("fed.store_scatter"):
-            store = cohort_scatter(store, idx, nds, nopts,
-                                   carry.step + 1, d_layout, o_layout,
-                                   residual=new_res)
-        new_carry = CohortState(new_state.g, new_state.g_opt, store,
-                                new_state.server_d, new_state.step,
-                                new_state.key)
+            store = cohort_scatter(store, idx, nds, nopts, stamp,
+                                   d_layout, o_layout, residual=new_res)
+        g, g_opt, server_d, step, key = shared
+        new_carry = CohortState(g, g_opt, store, server_d, step, key)
         metrics = dict(metrics, mean_age=jnp.mean(ages.astype(jnp.float32)))
         return new_carry, metrics
 
     return round_fn
+
+
+def _cohort_chunk(round_fn, adaptive: bool) -> Callable:
+    """``chunk(cstate, reals, idx, wts=None, valid=None)``: one scan of
+    ``round_fn`` over the window, which masks its own padded rounds."""
+
+    def chunk(cstate: CohortState, reals, idx, wts=None, valid=None):
+        assert (wts is not None) == adaptive, \
+            "wts must be supplied iff the engine was built adaptive=True"
+        return jax.lax.scan(round_fn, cstate, (reals, idx, wts, valid))
+
+    return chunk
 
 
 def make_cohort_engine(pair, fcfg: DistGANConfig, approach: str,
@@ -261,15 +300,7 @@ def make_cohort_engine(pair, fcfg: DistGANConfig, approach: str,
     The flag gates the extra input so the default path traces the EXACT
     program pinned bitwise against the plain fused engine.
     """
-    round_fn = _cohort_round_fn(pair, fcfg, approach)
-
-    def chunk(cstate: CohortState, reals, idx, wts=None, valid=None):
-        assert (wts is not None) == adaptive, \
-            "wts must be supplied iff the engine was built adaptive=True"
-        inp = (reals, idx) if wts is None else (reals, idx, wts)
-        if valid is None:
-            return jax.lax.scan(round_fn, cstate, inp)
-        return jax.lax.scan(_masked(round_fn), cstate, (inp, valid))
+    chunk = _cohort_chunk(_cohort_round_fn(pair, fcfg, approach), adaptive)
 
     # NOT donated: in-place scatter into a donated (U, N) carry lets XLA
     # reschedule the update clusters and the trajectory drifts at ULP from
@@ -281,13 +312,16 @@ def make_cohort_engine(pair, fcfg: DistGANConfig, approach: str,
 def make_fused_store_engine(pair, fcfg: DistGANConfig, approach: str,
                             adaptive: bool = False) -> Callable:
     """Store-resident fused window engine: ``make_cohort_engine``'s EXACT
-    trace — K gather→train→scatter rounds in one ``lax.scan`` over the
-    resident (U, N) store — with the carry DONATED, so XLA scatters the
-    cohort rows into the store in place.  One dispatch per window, zero
-    host traffic, and no per-chunk (U, N) store copy: at U=4096 the copy
-    is the dominant per-window cost of the non-donated engine, which is
-    kept solely for its C == U bitwise pin against the non-virtualized
-    engine (see the donation note there).
+    trace — K read→train→scatter rounds in one ``lax.scan`` over the
+    resident (U, N) store, padded rounds masked on their C rows — with
+    the carry DONATED, so XLA scatters the cohort rows into the store in
+    place.  Every per-round store access is a C-row access (row reads,
+    row-granular mask, row updates), so a round costs C rows of store
+    traffic whatever U is.  One dispatch per window, zero host traffic,
+    and no per-chunk (U, N) store copy: at U=4096 the copy is the
+    dominant per-window cost of the non-donated engine, which is kept
+    solely for its C == U bitwise pin against the non-virtualized engine
+    (see the donation note there).
 
     The caller must treat the passed ``cstate`` as consumed (rebind to
     the returned carry — ``core.session._drive_chunks`` already does).
@@ -299,16 +333,7 @@ def make_fused_store_engine(pair, fcfg: DistGANConfig, approach: str,
     per-round rows path carries (an extra optimization_barrier on the
     store does NOT recover bitwise; probed empirically).
     """
-    round_fn = _cohort_round_fn(pair, fcfg, approach)
-
-    def chunk(cstate: CohortState, reals, idx, wts=None, valid=None):
-        assert (wts is not None) == adaptive, \
-            "wts must be supplied iff the engine was built adaptive=True"
-        inp = (reals, idx) if wts is None else (reals, idx, wts)
-        if valid is None:
-            return jax.lax.scan(round_fn, cstate, inp)
-        return jax.lax.scan(_masked(round_fn), cstate, (inp, valid))
-
+    chunk = _cohort_chunk(_cohort_round_fn(pair, fcfg, approach), adaptive)
     return jax.jit(chunk, donate_argnums=(0,))
 
 

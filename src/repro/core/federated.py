@@ -162,11 +162,22 @@ def cohort_gather(store: CohortStore, idx, d_layout: FlatLayout,
     return ds, opts
 
 
+def take_rows(buf, idx):
+    """Rows ``idx`` (C,) of ``buf`` (U, ...) as one (C, ...) array, read as
+    C dynamic row slices.  ``buf[idx]`` is an XLA gather, which the TPU
+    compiler may lower as slices of the whole (U, N) buffer; C is a
+    trace-time constant, so C row slices touch only the C rows.  Indices
+    must lie in [0, U)."""
+    return jnp.concatenate([jax.lax.dynamic_index_in_dim(buf, idx[i], 0)
+                            for i in range(idx.shape[0])])
+
+
 def cohort_scatter(store: CohortStore, idx, ds, d_opts, round_idx,
                    d_layout: FlatLayout, opt_layout: FlatLayout,
                    residual=None) -> CohortStore:
     """Write updated cohort slices back into the store (row replacement —
-    values land bit-exactly) and stamp the members' ``last_round``.
+    values land bit-exactly) and stamp the members' ``last_round`` with
+    ``round_idx`` (a scalar, or one stamp a member).
     ``residual`` scatters the cohort's updated error-feedback rows when
     the store carries them (required iff ``store.residual`` exists)."""
     assert (residual is None) == (store.residual is None), \

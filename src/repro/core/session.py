@@ -841,7 +841,8 @@ class DeviceBackendDriver(BackendDriver):
             idx = _chunk_slice(sched_dev, start, k, rpj)
             w = (None if wts_dev is None
                  else _chunk_slice(wts_dev, start, k, rpj))
-            with jax.profiler.TraceAnnotation("fed.dispatch", window=window):
+            with jax.profiler.TraceAnnotation("fed.dispatch", window=window,
+                                              masked=rpj - k):
                 return self.eng(cstate, reals, idx, wts=w,
                                 valid=_valid_mask(k, rpj))
 

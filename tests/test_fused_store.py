@@ -141,11 +141,26 @@ def test_fused_store_is_deterministic_and_shares_one_program():
     assert eng._cache_size() == 1            # ONE program, both chunks
 
 
-def test_fused_store_remainder_matches_unpadded():
+def _host(tree):
+    """Host copy of a carry, PRNG keys as their raw data."""
+    def one(x):
+        if jax.dtypes.issubdtype(x.dtype, jax.dtypes.prng_key):
+            x = jax.random.key_data(x)
+        return np.asarray(x)
+    return jax.tree.map(one, tree)
+
+
+@pytest.mark.parametrize("padding", ["repeat_last", "one_user"])
+@pytest.mark.parametrize("codec", ["none", "topk_int8"])
+def test_fused_store_remainder_matches_unpadded(codec, padding):
     """A masked padded chunk never touches the carry: chunked driving
-    lands on the same store as one unpadded call."""
+    lands on the same store as one unpadded call, and a masked round is
+    bitwise a no-op on every store buffer (error-feedback residual
+    included) — also when a padded round repeats one user index, since
+    each duplicate writes back the same unchanged row."""
     U, C, K, rpj = 8, 3, 5, 4
-    fcfg = DistGANConfig(num_users=U, selection="topk", upload_frac=0.3)
+    fcfg = DistGANConfig(num_users=U, selection="topk", upload_frac=0.3,
+                         codec=codec, error_feedback=codec != "none")
     rng = np.random.default_rng(0)
     reals = rng.normal(size=(K, C, 16, 2)).astype(np.float32)
     sched = make_schedule("round_robin", U, C, K, np.random.default_rng(1))
@@ -159,9 +174,21 @@ def test_fused_store_remainder_matches_unpadded():
     for i in range(0, K, rpj):
         k = min(rpj, K - i)
         r = jnp.asarray(_pad_to(reals[i:i + k], rpj))
-        s = jnp.asarray(_pad_to(sched[i:i + k], rpj))
-        c2, m = eng(c2, r, s, None, jnp.asarray(np.arange(rpj) < k))
+        s = _pad_to(sched[i:i + k], rpj)
+        trained = np.unique(sched[i:i + k])
+        if padding == "one_user" and k < rpj:
+            # every padded round names one user no valid round trains
+            s[k:] = np.setdiff1d(np.arange(U), trained)[0]
+        before = _host(c2.store)
+        c2, m = eng(c2, r, jnp.asarray(s), None,
+                    jnp.asarray(np.arange(rpj) < k))
         g2.append(np.asarray(m["g_loss"])[:k])
+        # rows no valid round trains are bitwise as they were, whatever
+        # the padded rounds name
+        untouched = np.setdiff1d(np.arange(U), trained)
+        for b, a in zip(jax.tree.leaves(before),
+                        jax.tree.leaves(_host(c2.store))):
+            np.testing.assert_array_equal(a[untouched], b[untouched])
     # chunked-vs-whole reuses the scan-tiling 1e-6 contract; last_round
     # is exact either way
     np.testing.assert_allclose(g1, np.concatenate(g2), rtol=0, atol=1e-6)
@@ -170,6 +197,13 @@ def test_fused_store_remainder_matches_unpadded():
     np.testing.assert_allclose(np.asarray(c1.store.d_flat),
                                np.asarray(c2.store.d_flat),
                                rtol=0, atol=1e-6)
+
+    # a chunk of masked rounds only leaves the whole carry bitwise
+    before = _host(c2)
+    c3, _ = eng(c2, r, jnp.asarray(s), None, jnp.zeros((rpj,), bool))
+    after = _host(c3)
+    assert (after.store.residual is None) == (codec == "none")
+    jax.tree.map(np.testing.assert_array_equal, after, before)
 
 
 # ---------------------------------------------------------------------------

@@ -130,6 +130,19 @@ def test_session_spans_nest_inside_each_window(tmp_path):
         assert sample[2] <= h2d[1]
 
 
+def test_dispatch_span_counts_the_masked_rounds(tmp_path):
+    sess = _session()
+    sess.run(1)                                  # compiles outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        sess.run(K)
+        sess.run(1)                              # K - 1 padded rounds
+    finally:
+        jax.profiler.stop_trace()
+    assert [s[3]["masked"] for s in _spans(tmp_path)
+            if s[0] == "fed.dispatch"] == [0, K - 1]
+
+
 def test_compile_s_counts_only_the_window_s_compiles():
     sess = _session()
     first = sess.run(K)

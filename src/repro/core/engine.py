@@ -66,8 +66,8 @@ from repro.core.approaches import (DistGANConfig, DistGANState, _opts,
                                    d_flat_layout, d_opt_flat_layout,
                                    init_state)
 from repro.core.federated import (CohortStore, HostStateBackend,
-                                  cohort_gather, cohort_scatter,
-                                  make_cohort_store, take_rows)
+                                  cohort_scatter, make_cohort_store,
+                                  take_rows)
 from repro.core.spec import DEFAULT_ROUNDS_PER_JIT, resolve_approach
 
 
@@ -182,17 +182,24 @@ def init_cohort_state(pair, fcfg: DistGANConfig, key, *,
     return CohortState(st.g, st.g_opt, store, st.server_d, st.step, st.key)
 
 
+def store_ds(pair, store: CohortStore):
+    """All U discriminator rows of the store as the stacked (U, ...) tree
+    (each leaf a column slice of ``d_flat``: bitwise the rows)."""
+    return d_flat_layout(pair).unflatten_stacked(store.d_flat)
+
+
+def store_d_opts(pair, fcfg: DistGANConfig, store: CohortStore):
+    """All U optimizer rows of the store as the stacked (U, ...) tree."""
+    return d_opt_flat_layout(pair, fcfg).unflatten_stacked(store.opt_flat)
+
+
 def cohort_state_to_full(pair, fcfg: DistGANConfig,
                          cstate: CohortState) -> DistGANState:
     """Unpack the store back into the stacked-tree DistGANState layout
     (evaluation / checkpointing interop)."""
-    d_layout = d_flat_layout(pair)
-    o_layout = d_opt_flat_layout(pair, fcfg)
-    ds, d_opts = cohort_gather(cstate.store,
-                               jnp.arange(cstate.store.num_users),
-                               d_layout, o_layout)
-    return DistGANState(cstate.g, cstate.g_opt, ds, d_opts, cstate.server_d,
-                        cstate.step, cstate.key)
+    return DistGANState(cstate.g, cstate.g_opt, store_ds(pair, cstate.store),
+                        store_d_opts(pair, fcfg, cstate.store),
+                        cstate.server_d, cstate.step, cstate.key)
 
 
 def _cohort_round_fn(pair, fcfg: DistGANConfig, approach: str) -> Callable:

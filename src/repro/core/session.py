@@ -51,14 +51,15 @@ import jax.numpy as jnp
 
 from repro.checkpoint.msgpack_ckpt import (latest_step, restore_checkpoint,
                                            save_checkpoint)
-from repro.core.approaches import (DistGANConfig, d_flat_layout,
-                                   init_state)
+from repro.core.approaches import (DistGANConfig, DistGANState,
+                                   d_flat_layout, init_state)
 from repro.core.engine import (CohortShared, CohortState, _pad_to,
                                _wants_residual, cohort_state_to_full,
                                init_cohort_state, init_host_backend,
                                make_cohort_engine, make_cohort_rows_engine,
                                make_engine, make_fused_store_engine,
-                               make_superbatch_engine)
+                               make_superbatch_engine, store_d_opts,
+                               store_ds)
 from repro.core.federated import (make_schedule_source,
                                   participation_weights, upload_bytes_flat,
                                   window_forwarding)
@@ -78,12 +79,95 @@ _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 @dataclasses.dataclass
 class RunResult:
+    """One window's result.  ``state`` is the training state after the
+    window: a ``DistGANState`` on the plain device engines and the
+    streaming backends (``None`` there under ``materialize_state=False``),
+    a :class:`CohortStateView` on the device cohort path, which reads the
+    window's carry and unpacks the (U, N) store only when ``ds`` or
+    ``d_opts`` is read.  On the donating engines (``make_engine``,
+    ``fuse_store_rounds``) the state is only readable until the next
+    ``run()``."""
+
     g_losses: np.ndarray           # (steps,)
     d_losses: np.ndarray           # (steps, U) — (steps, C) under cohorting
     step_time_s: float             # steady-state per-step (post-compile)
     samples: np.ndarray | None
-    state: typing.Any              # DistGANState | None
+    state: typing.Any              # DistGANState | CohortStateView | None
     extra: dict
+
+
+class CohortStateView:
+    """Read-only ``DistGANState`` view over a cohort window's final carry.
+
+    ``g``, ``g_opt``, ``server_d``, ``step`` and ``key`` are the carry's
+    own arrays.  ``ds`` and ``d_opts`` unpack the store into the stacked
+    (U, ...) trees on first read, each on its own and under a
+    ``fed.unpack`` span tagged with the window and the ``part``, and are
+    cached; ``to_full()`` gives the whole ``DistGANState``.
+    ``block_until_ready()`` (which ``jax.block_until_ready`` calls on this
+    leaf) waits for the carry and unpacks nothing.  A carry donated to a
+    later ``run()`` raises on any read not yet cached."""
+
+    def __init__(self, pair, fcfg: DistGANConfig, carry: CohortState,
+                 window: int):
+        self._pair, self._fcfg = pair, fcfg
+        self._carry_state, self._window = carry, window
+        self._parts = {}
+
+    def _carry(self) -> CohortState:
+        if any(isinstance(leaf, jax.Array) and leaf.is_deleted()
+               for leaf in jax.tree.leaves(self._carry_state)):
+            raise RuntimeError(
+                f"RunResult.state of the window at round {self._window} "
+                f"was donated to a later run(): read RunResult.state "
+                f"before the next run()")
+        return self._carry_state
+
+    def _part(self, part: str, unpack: Callable):
+        if part not in self._parts:
+            store = self._carry().store
+            with jax.profiler.TraceAnnotation("fed.unpack",
+                                              window=self._window,
+                                              part=part):
+                self._parts[part] = unpack(store)
+        return self._parts[part]
+
+    @property
+    def g(self):
+        return self._carry().g
+
+    @property
+    def g_opt(self):
+        return self._carry().g_opt
+
+    @property
+    def server_d(self):
+        return self._carry().server_d
+
+    @property
+    def step(self):
+        return self._carry().step
+
+    @property
+    def key(self):
+        return self._carry().key
+
+    @property
+    def ds(self):
+        return self._part("ds", lambda s: store_ds(self._pair, s))
+
+    @property
+    def d_opts(self):
+        return self._part("d_opts",
+                          lambda s: store_d_opts(self._pair, self._fcfg, s))
+
+    def to_full(self) -> DistGANState:
+        return DistGANState(self.g, self.g_opt, self.ds, self.d_opts,
+                            self.server_d, self.step, self.key)
+
+    def block_until_ready(self) -> "CohortStateView":
+        jax.block_until_ready(self._carry())
+        return self
 
 
 def _merge_results(parts: list) -> RunResult:
@@ -865,14 +949,12 @@ class DeviceBackendDriver(BackendDriver):
         counts = np.bincount(schedule.ravel(), minlength=U)
         total = sess.round + rounds
         staleness = total - np.asarray(cstate.store.last_round)
-        with jax.profiler.TraceAnnotation("fed.unpack", window=window):
-            state = cohort_state_to_full(sess.pair, sess.fcfg, cstate)
         return RunResult(
             g_losses=g_losses,
             d_losses=d_losses,
             step_time_s=steady / step_denom,
             samples=sess._eval_samples(cstate.g),
-            state=state,
+            state=CohortStateView(sess.pair, sess.fcfg, cstate, window),
             extra={"kept_frac": kept_frac,
                    "engine": "fused", "min_step_time_s": min_step_s,
                    "participation": sess.spec.participation.scheduler,

@@ -27,19 +27,22 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core.approaches import DistGANConfig
-from repro.core.engine import (_pad_to, init_cohort_state,
+from repro.core.approaches import (DistGANConfig, DistGANState,
+                                   d_flat_layout, d_opt_flat_layout)
+from repro.core.engine import (_pad_to, cohort_state_to_full,
+                               init_cohort_state,
                                init_host_backend, make_cohort_engine,
                                make_cohort_rows_engine,
                                make_fused_store_engine,
                                make_superbatch_engine)
-from repro.core.federated import make_schedule, window_forwarding
+from repro.core.federated import (cohort_gather, make_schedule,
+                                  window_forwarding)
 from repro.core.gan import MLPGanConfig, make_mlp_pair
 from repro.core.protocol import run_distgan, stream_cohort_rounds
-from repro.core.session import (FederationSession,
+from repro.core.session import (CohortStateView, FederationSession,
                                 superbatch_cohort_rounds)
-from repro.core.spec import (BackendSpec, EngineSpec, FederationSpec,
-                             ParticipationSpec)
+from repro.core.spec import (BackendSpec, CombineSpec, CompressionSpec,
+                             EngineSpec, FederationSpec, ParticipationSpec)
 from repro.data.federated import FederatedDataset
 from repro.data.mixtures import make_user_domains
 
@@ -328,6 +331,79 @@ def test_session_async_falls_back_to_per_round():
                     fuse_store_rounds=True)
     assert r.extra["fused_store"] is False
     assert np.all(np.isfinite(r.g_losses))
+
+
+def _device_cohort_session(fused_store: bool, codec: str):
+    spec = FederationSpec(
+        approach="approach1", batch_size=16, seed=0, eval_samples=0,
+        engine=EngineSpec(kind="fused", rounds_per_jit=4,
+                          fuse_store_rounds=fused_store),
+        participation=ParticipationSpec("round_robin", cohort_size=3),
+        backend=BackendSpec("device"),
+        combine=CombineSpec(compression=CompressionSpec(
+            codec=codec, error_feedback=codec != "none")))
+    fcfg = DistGANConfig(num_users=8, selection="topk", upload_frac=0.3)
+    return FederationSession(PAIR, fcfg, _ds(8), spec)
+
+
+def _unpacked(sess):
+    """Host copy of ``cohort_state_to_full`` of the driver's carry, and
+    of the store's rows by the identity gather it made before the view."""
+    carry = sess._driver.cstate
+    full = _host(cohort_state_to_full(PAIR, sess.fcfg, carry))
+    rows = _host(cohort_gather(carry.store, jnp.arange(8),
+                               d_flat_layout(PAIR),
+                               d_opt_flat_layout(PAIR, sess.fcfg)))
+    return full, rows
+
+
+def _assert_state_equal(view, full):
+    for name in DistGANState._fields:
+        jax.tree.map(np.testing.assert_array_equal,
+                     _host(getattr(view, name)), getattr(full, name))
+
+
+@pytest.mark.parametrize("codec", ["none", "topk_int8"])
+@pytest.mark.parametrize("fused_store", [False, True])
+def test_cohort_result_state_is_a_view_of_the_carry(fused_store, codec):
+    """The device cohort path's ``RunResult.state`` reads the window's
+    carry: every field bitwise what ``cohort_state_to_full`` gives, the
+    shared leaves with no copy, and ``jax.block_until_ready`` unpacks
+    nothing.  On the donating fused-store engine a view whose carry went
+    into the next run() raises a named error; on the plain cohort engine
+    it stays readable and unchanged."""
+    sess = _device_cohort_session(fused_store, codec)
+    r1 = sess.run(4)
+    full1, _ = _unpacked(sess)
+    r2 = sess.run(5)
+    full2, (ds_rows, opt_rows) = _unpacked(sess)
+    carry = sess._driver.cstate
+    view = r2.state
+    assert isinstance(view, CohortStateView)
+    assert (carry.store.residual is None) == (codec == "none")
+    assert jax.block_until_ready(view) is view
+    assert not view._parts                   # blocking unpacked nothing
+    for name in ("g", "g_opt", "server_d", "step", "key"):
+        for a, b in zip(jax.tree.leaves(getattr(view, name)),
+                        jax.tree.leaves(getattr(carry, name))):
+            assert a is b
+    _assert_state_equal(view, full2)
+    _assert_state_equal(view.to_full(), full2)
+    jax.tree.map(np.testing.assert_array_equal, _host(view.ds), ds_rows)
+    jax.tree.map(np.testing.assert_array_equal, _host(view.d_opts), opt_rows)
+
+    sess.run(3)
+    # parts read before the next run() are kept
+    jax.tree.map(np.testing.assert_array_equal, _host(view.ds), full2.ds)
+    if fused_store:
+        for read in (lambda s: s.ds, lambda s: s.g,
+                     lambda s: s.block_until_ready()):
+            with pytest.raises(RuntimeError,
+                               match="before the next run"):
+                read(r1.state)
+    else:
+        _assert_state_equal(r1.state, full1)
+        _assert_state_equal(view, full2)
 
 
 def _fused_host_session(ds, fcfg, rpj=4):

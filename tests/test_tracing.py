@@ -120,14 +120,41 @@ def test_session_spans_nest_inside_each_window(tmp_path):
         assert {s[3]["window"] for s in inner} == {run[3]["window"]}
         names = [s[0] for s in inner]
         # one prestaged window of batches, then a dispatch and a sync a
-        # chunk, then the unpack of the store
+        # chunk; the store is unpacked only when RunResult.state is read
         chunks = run[3]["rounds"] // K
         assert names == (["fed.sample", "fed.h2d"]
-                         + ["fed.dispatch", "fed.sync"] * chunks
-                         + ["fed.unpack"])
+                         + ["fed.dispatch", "fed.sync"] * chunks)
         sample, h2d = inner[0], inner[1]
         assert sample[3]["bytes"] == h2d[3]["bytes"] > 0
         assert sample[2] <= h2d[1]
+
+
+def test_unpack_span_opens_once_for_each_part_read(tmp_path):
+    """The store is unpacked only for the parts of RunResult.state that
+    are read, each once, tagged with the window of the run that made the
+    state."""
+    sess = _session()
+    sess.run(K)                                  # compiles outside the trace
+    jax.profiler.start_trace(str(tmp_path / "window"))
+    try:
+        res = sess.run(K)
+        jax.block_until_ready(res.state)
+        res.state.g
+    finally:
+        jax.profiler.stop_trace()
+    names = [s[0] for s in _spans(tmp_path / "window")]
+    assert "fed.run" in names and "fed.unpack" not in names
+    jax.profiler.start_trace(str(tmp_path / "reads"))
+    try:
+        res.state.ds
+        res.state.ds                             # cached: no second unpack
+        res.state.d_opts
+        res.state.to_full()
+    finally:
+        jax.profiler.stop_trace()
+    assert [(s[0], s[3]["window"], s[3]["part"])
+            for s in _spans(tmp_path / "reads")] == [
+        ("fed.unpack", K, "ds"), ("fed.unpack", K, "d_opts")]
 
 
 def test_dispatch_span_counts_the_masked_rounds(tmp_path):
